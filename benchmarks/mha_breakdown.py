@@ -118,7 +118,7 @@ def bwd_rows(out, L=256, block=16, smoke=False):
     before; the plan run (KT*) is the after."""
     from repro.core.sparse_attention import bcsr_transpose
     from repro.kernels.block_sparse_attn import (_fused_dkv, _fused_dq,
-                                                 _fused_forward)
+                                                 _fused_forward, _lane_pad)
     from repro.kernels.dispatch import default_interpret
 
     if smoke:
@@ -138,15 +138,17 @@ def bwd_rows(out, L=256, block=16, smoke=False):
     q = jax.random.normal(key, (N, G, L, hd))
     k = jax.random.normal(jax.random.fold_in(key, 1), (N, L, hd))
     v = jax.random.normal(jax.random.fold_in(key, 2), (N, L, hd))
+    # the kernel wrappers take lane-padded operands, as _fused_op passes them
+    q, k, v = _lane_pad(q), _lane_pad(k), _lane_pad(v)
     # layer 1 (global stripe) is the interesting one: its own population is
     # what drives KT*
     col = jnp.maximum(jnp.asarray(col_st[1]), 0)
     nv = jnp.asarray(nv_st[1])
     kw = dict(block=block, causal=True, sliding_window=None,
-              interpret=default_interpret(None))
+              interpret=default_interpret(None), head_dim=hd)
     o, lse = _fused_forward(q, k, v, col, nv, **kw)
     do = jax.random.normal(jax.random.fold_in(key, 3), o.shape)
-    delta = jnp.sum(do * o, -1)
+    delta = jnp.sum(do * o, -1, keepdims=True)
 
     t_dq = _time(jax.jit(lambda: _fused_dq(q, k, v, do, lse, delta, col, nv,
                                            **kw)))

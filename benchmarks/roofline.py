@@ -52,7 +52,7 @@ def kernel_rows(out, smoke=False):
     from repro.core.sparse_attention import (bcsr_from_blockmask,
                                              bcsr_transpose)
     from repro.kernels.block_sparse_attn import (_fused_dkv, _fused_dq,
-                                                 _fused_forward)
+                                                 _fused_forward, _lane_pad)
     from repro.kernels.dispatch import default_interpret
 
     L, block, hd, N, G = (128, 16, 32, 2, 1) if smoke else (256, 32, 32, 2, 1)
@@ -69,11 +69,13 @@ def kernel_rows(out, smoke=False):
     q = jax.random.normal(key, (N, G, L, hd))
     k = jax.random.normal(jax.random.fold_in(key, 1), (N, L, hd))
     v = jax.random.normal(jax.random.fold_in(key, 2), (N, L, hd))
+    # the kernel wrappers take lane-padded operands, as _fused_op passes them
+    q, k, v = _lane_pad(q), _lane_pad(k), _lane_pad(v)
     kw = dict(block=block, causal=False, sliding_window=None,
-              interpret=default_interpret(None))
+              interpret=default_interpret(None), head_dim=hd)
     o, lse = _fused_forward(q, k, v, col, nv, **kw)
     do = jax.random.normal(jax.random.fold_in(key, 3), o.shape)
-    delta = jnp.sum(do * o, -1)
+    delta = jnp.sum(do * o, -1, keepdims=True)
     ri, nvt = bcsr_transpose(col, nv, ncb=n)
 
     bb = block * block

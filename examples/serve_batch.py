@@ -14,6 +14,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import Request, ServeEngine
 from repro.models.registry import build
 
@@ -45,6 +46,7 @@ def serve(eng, reqs, label):
 
 
 def main():
+    enable_compile_cache()
     cfg = get_config("qwen2-7b").reduced().replace(remat=False)
     bundle = build(cfg)
     params = bundle.init(jax.random.key(0))

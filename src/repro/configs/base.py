@@ -71,11 +71,10 @@ class SpionConfig:
     # None -> derived from the generated pattern at transition time.
     max_blocks_per_row: Optional[int] = None
     # sparse-phase attention implementation: "auto" picks the fused
-    # differentiable Pallas kernel where its compiled lane exists (TPU
-    # Mosaic today; under a mesh, whenever a kernel dim shards) and the
-    # pure-jnp BCSR path elsewhere; "fused" / "jnp" force one (fused on
-    # CPU runs the Pallas interpreter — correct but slow, used by the
-    # gradient tests; on GPU it engages the Triton lowering).
+    # differentiable Pallas kernel on TPU (Mosaic; under a mesh, whenever a
+    # kernel dim shards) and the pure-jnp BCSR path elsewhere; "fused" /
+    # "jnp" force one (fused on CPU runs the Pallas interpreter — correct
+    # but slow, used by the gradient tests).
     kernel: str = "auto"
 
 

@@ -58,10 +58,11 @@ def resolve_kernel(cfg, batch: int, kv_heads: int, *, nrb=None, halo=None,
     branch is deliberately NOT gated on the TPU backend: CI's
     virtual-device meshes and the dry-run must exercise the exact
     production dispatch (shard_map + kernel), accepting the Pallas
-    interpreter's speed off-TPU — a real multi-host CPU/GPU deployment that
-    wants wall-clock should force kernel="jnp". When nothing divides, or
-    with no mesh on a non-TPU backend, "auto" falls back to the jnp BCSR
-    path (the GSPMD-compatible gather stand-in). `prefer` overrides
+    interpreter's speed off-TPU. When nothing divides, "auto" falls back to
+    the jnp BCSR path (the GSPMD-compatible gather stand-in). With no mesh,
+    "auto" is the Mosaic kernel on TPU whatever the local chip count, and
+    the jnp path off TPU (the kernels have no other compiled lowering).
+    `prefer` overrides
     cfg.spion.kernel (an exec pinned to one impl). Exposed separately so
     dry-runs and tests can record the resolution without tracing a step."""
     impl = prefer or getattr(cfg.spion, "kernel", "auto")
@@ -75,16 +76,8 @@ def resolve_kernel(cfg, batch: int, kv_heads: int, *, nrb=None, halo=None,
         baxes, kv_ax = kernel_shard_axes(mesh, batch, kv_heads)
         seq_ax, _ = kernel_seq_axis(mesh, nrb, halo)
         return "fused" if (baxes or kv_ax or seq_ax) else "jnp"
-    # meshless: "auto" takes the compiled kernel lane only where the
-    # Mosaic port exists today (TPU, single device; with multiple devices
-    # but no mesh there is nothing to shard over). GPU counts as a
-    # compiled backend in kernels/dispatch (no silent interpreter), but
-    # the prefetch-grid kernels have not been ported to Triton yet, so
-    # "auto" stays on jnp there — an explicit kernel="fused" engages the
-    # Triton lane and fails loudly if lowering is unsupported.
     from repro.kernels.dispatch import compiled_backend
-    on_tpu = compiled_backend() == "tpu" and jax.device_count() == 1
-    return "fused" if on_tpu else "jnp"
+    return "fused" if compiled_backend() == "tpu" else "jnp"
 
 
 @jax.tree_util.register_pytree_node_class

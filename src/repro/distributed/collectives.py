@@ -12,7 +12,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.optim.grad import compressed_psum
@@ -31,8 +30,8 @@ def compressed_grad_sync(mesh: Mesh, grads, axes=("data",)):
     def f(g):
         return compressed_psum(g, axes if len(axes) > 1 else axes[0])
 
-    fn = shard_map(f, mesh=mesh, in_specs=(specs,), out_specs=specs,
-                   check_rep=False)
+    fn = jax.shard_map(f, mesh=mesh, in_specs=(specs,), out_specs=specs,
+                       check_vma=False)
     return fn(grads)
 
 
@@ -47,6 +46,6 @@ def hierarchical_grad_sync(mesh: Mesh, grads):
             g = compressed_psum(g, "pod")
         return g
 
-    fn = shard_map(f, mesh=mesh, in_specs=(specs,), out_specs=specs,
-                   check_rep=False)
+    fn = jax.shard_map(f, mesh=mesh, in_specs=(specs,), out_specs=specs,
+                       check_vma=False)
     return fn(grads)

@@ -13,7 +13,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -61,8 +60,8 @@ def pipeline_apply(mesh: Mesh, axis: str, stage_fn, stage_params, x, n_micro: in
             jnp.where(idx == S - 1, outputs, jnp.zeros_like(outputs)), axis)
         return outputs
 
-    fn = shard_map(inner, mesh=mesh,
-                   in_specs=(pspecs, P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(inner, mesh=mesh,
+                       in_specs=(pspecs, P()), out_specs=P(),
+                       check_vma=False)
     out = fn(stage_params, xs)
     return out.reshape((B,) + x.shape[1:])

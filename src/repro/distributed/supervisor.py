@@ -36,10 +36,13 @@ a crash-loop into a clean failure instead of an infinite burn.
 
 Everything here is plain-process logic (no jax calls): the supervisor must
 stay alive and responsive precisely when the jax runtime inside the workers
-is the thing that is broken.
+is the thing that is broken. That includes the chip check: a TPU belongs to
+one process at a time and one worker drives all of a host's chips, so on a
+TPU host the supervisor refuses to start more than one worker.
 """
 from __future__ import annotations
 
+import glob
 import os
 import signal
 import socket
@@ -48,6 +51,30 @@ import time
 from typing import Callable, List, Optional, Sequence
 
 from repro.distributed.fault import Heartbeat
+
+
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+# PCI device ids of TPU chips (v3, v4, v5p, v5e, v6e, 7x); the same vendor
+# also makes non-TPU devices such as the gVNIC network card
+_TPU_PCI_DEVICES = frozenset(
+    ("0x0027", "0x005e", "0x0062", "0x0063", "0x006f", "0x0076"))
+
+
+def local_tpu_chips(pci_root: str = "/sys/bus/pci/devices") -> int:
+    """TPU chips attached to this host, counted from PCI vendor/device ids
+    as the TPU runtime does — no jax, which would take a chip for the
+    supervisor itself."""
+    n = 0
+    for vendor in glob.glob(os.path.join(pci_root, "*", "vendor")):
+        dev = os.path.join(os.path.dirname(vendor), "device")
+        try:
+            with open(vendor) as f, open(dev) as g:
+                if (f.read().strip() == _GOOGLE_PCI_VENDOR
+                        and g.read().strip() in _TPU_PCI_DEVICES):
+                    n += 1
+        except OSError:
+            continue
+    return n
 
 
 def free_port() -> int:
@@ -104,6 +131,10 @@ class FleetSupervisor:
     environment (a fresh coordinator port per generation — the old port may
     linger in TIME_WAIT after a kill). Workers inherit the supervisor's
     stdout/stderr so logs interleave into one stream a launcher can tail.
+
+    On a TPU host construction refuses nproc > 1: one worker drives every
+    local chip, and each worker after the first would fail on the TPU
+    library's lock.
     """
 
     def __init__(self, worker_cmd: Sequence[str], nproc: int, ckpt_dir: str,
@@ -127,11 +158,23 @@ class FleetSupervisor:
         self.straggler_limit = straggler_limit
         self.coordinator_host = coordinator_host
         self.env = dict(os.environ) if env is None else dict(env)
+        self._check_one_worker_per_tpu_host()
         self.sleep_fn = sleep_fn
         self.log = log
         self.respawns = 0
         self.generation = 0
         self._procs: List[subprocess.Popen] = []
+
+    def _check_one_worker_per_tpu_host(self):
+        if self.nproc <= 1 or "tpu" not in self.env.get("JAX_PLATFORMS",
+                                                         "tpu"):
+            return
+        chips = local_tpu_chips()
+        if chips:
+            raise RuntimeError(
+                f"FleetSupervisor: {self.nproc} workers on a TPU host with "
+                f"{chips} chips. A chip belongs to one process: run one "
+                f"worker per host, which drives all local chips.")
 
     # -- heartbeat plumbing -------------------------------------------------
 

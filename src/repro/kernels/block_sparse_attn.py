@@ -18,13 +18,13 @@ probabilities reconstruct exactly as p = exp(s - lse) in the backward.
 
 Double-buffered BCSR fetch (DESIGN.md §15): the gathered operands — K/V
 tiles in the forward and dQ, Q/dO/lse/delta row slices in dK/dV — live in
-HBM (`pltpu.ANY`) and are DMA'd into a `depth`-slot VMEM ring with
+HBM (`pltpu.HBM`) and are DMA'd into a `depth`-slot VMEM ring with
 `pltpu.make_async_copy`, so the NEXT column block's fetch overlaps the
 CURRENT block's matmul. Schedule per grid step (K = table width):
 prologue starts DMAs 0..depth-2; loop iteration i first starts DMA
 i+depth-1 into the slot iteration i-1 just drained, then waits DMA i
 (slot i % depth) and computes. depth=1 degenerates to a synchronous
-fetch; the depth (and the Mosaic/Triton lowering knobs) come from the
+fetch; the depth (and the Mosaic grid semantics) come from the
 `KernelConfig` the autotuner picked (kernels/autotune.py). Entries past
 `nvalid` fetch a (clamped, in-range) tile unconditionally and are masked
 out of the flash update as exact no-ops — uniform DMA traffic keeps the
@@ -80,6 +80,20 @@ from repro.kernels.dispatch import (DEFAULT_CONFIG, KernelConfig,
                                     in_sharded_body)
 
 NEG = -1e30
+LANES = 128
+
+
+def _lane_pad(x):
+    """Zero-pad the minor (head) axis to a multiple of 128 lanes. Mosaic
+    DMAs whole lane tiles, so a narrower head dim cannot be sliced out of
+    HBM; the zero columns add nothing to q.k^T or p.v. `_fused_op` owns
+    this layout: it pads once per forward and once per backward, hands the
+    padded operands to the three kernel wrappers, and slices the head dim
+    back off their outputs."""
+    pad = -x.shape[-1] % LANES
+    if not pad:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
 
 
 def _tile_mask(r, col, block, causal, sliding_window):
@@ -100,35 +114,21 @@ def _depth(config, width):
 
 
 def _compiler_params(config, interpret, default_semantics):
-    """Backend-specific lowering knobs from the tuned KernelConfig.
+    """Mosaic lowering knobs from the tuned KernelConfig.
 
-    None in interpret mode (nothing lowers) and on unknown backends.
-    Mosaic gets dimension_semantics — config's for the fwd/dQ grids,
+    None in interpret mode (nothing lowers) and off TPU. Mosaic gets
+    dimension_semantics — config's for the fwd/dQ grids,
     `default_semantics` verbatim where the grid has mandatory-sequential
-    dims (dK/dV's innermost g). Triton gets num_warps / num_stages."""
-    if interpret or config is None:
+    dims (dK/dV's innermost g)."""
+    if interpret or config is None or compiled_backend() != "tpu":
         return None
-    backend = compiled_backend()
-    if backend == "tpu":
-        sem = default_semantics
-        if config.dimension_semantics is not None and \
-                default_semantics is not None and \
-                "arbitrary" not in default_semantics:
-            rank = len(default_semantics)
-            sem = tuple(config.dimension_semantics)[:rank]
-            sem += ("arbitrary",) * (rank - len(sem))
-        if sem is None:
-            return None
-        return pltpu.TPUCompilerParams(dimension_semantics=sem)
-    if backend == "gpu":
-        from jax.experimental.pallas import triton as pltriton
-        kw = {}
-        if config.num_warps is not None:
-            kw["num_warps"] = int(config.num_warps)
-        if config.num_stages is not None:
-            kw["num_stages"] = int(config.num_stages)
-        return pltriton.TritonCompilerParams(**kw) if kw else None
-    return None
+    sem = default_semantics
+    if config.dimension_semantics is not None and \
+            "arbitrary" not in default_semantics:
+        rank = len(default_semantics)
+        sem = tuple(config.dimension_semantics)[:rank]
+        sem += ("arbitrary",) * (rank - len(sem))
+    return pltpu.CompilerParams(dimension_semantics=sem)
 
 
 # ---------------------------------------------------------------------------
@@ -157,6 +157,8 @@ def _fwd_kernel(col_ref, nvalid_ref, off_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     q = q_ref[0, 0].astype(jnp.float32)          # (B, hd)
 
+    # row statistics are (B, 1) columns, never 1-D vectors: Mosaic lays
+    # out 2-D values only, and a column broadcasts across a (B, B) tile
     def step(i, carry):
         m_prev, l_prev, acc = carry
         ahead = i + depth - 1
@@ -179,43 +181,44 @@ def _fwd_kernel(col_ref, nvalid_ref, off_ref, q_ref, k_hbm, v_hbm, o_ref,
         # entries past nvalid are fetched (uniform DMA schedule) but are
         # exact no-ops on the flash carry: s=NEG keeps m, alpha=exp(0)=1,
         # p=0 adds nothing to l or acc
-        ok &= jnp.full((block, block), i < nv)
+        ok &= i < nv
         s = jnp.where(ok, s, NEG)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)                     # rescale factor
-        p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
-        l_new = l_prev * alpha + jnp.sum(p, -1)
-        acc = acc * alpha[:, None] + jax.lax.dot_general(
+        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+        l_new = l_prev * alpha + jnp.sum(p, -1, keepdims=True)
+        acc = acc * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         return m_new, l_new, acc
 
     m, l, acc = jax.lax.fori_loop(
-        0, K, step, (jnp.full((block,), NEG, jnp.float32),
-                     jnp.zeros((block,), jnp.float32),
+        0, K, step, (jnp.full((block, 1), NEG, jnp.float32),
+                     jnp.zeros((block, 1), jnp.float32),
                      jnp.zeros((block, hd), jnp.float32)))
 
     # Alg. 6 line 15 zero-correction: pruned positions count exp(0 - m).
     # Row positions are GLOBAL (off_ref[0] rebases seq-shard-local rows).
     rows = (r + off_ref[0]) * block + \
-        jax.lax.broadcasted_iota(jnp.int32, (block,), 0)
+        jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
     if causal:
         rt = (rows + 1).astype(jnp.float32)
         if sliding_window is not None:
             rt = jnp.minimum(rt, float(sliding_window))
     else:
-        rt = jnp.full((block,), float(seq_len))
+        rt = jnp.full((block, 1), float(seq_len))
     # stored counts come from the same masks; recompute per active tile
 
     def count(i, acc_):
         ok = _tile_mask(r + off_ref[0], col_ref[r, i] + off_ref[1], block,
                         causal, sliding_window)
-        ok &= jnp.full((block, block), i < nv)
-        return acc_ + jnp.sum(ok.astype(jnp.float32), -1)
+        ok &= i < nv
+        return acc_ + jnp.sum(ok.astype(jnp.float32), -1, keepdims=True)
 
-    stored = jax.lax.fori_loop(0, K, count, jnp.zeros((block,), jnp.float32))
+    stored = jax.lax.fori_loop(0, K, count,
+                               jnp.zeros((block, 1), jnp.float32))
     denom = l + jnp.maximum(rt - stored, 0.0) * jnp.exp(-m)
     safe = jnp.where(denom == 0.0, 1.0, denom)
-    o_ref[0, 0] = (acc / safe[:, None]).astype(o_ref.dtype)
+    o_ref[0, 0] = (acc / safe).astype(o_ref.dtype)
     # rows with truly empty denominators get lse=+inf -> p = 0 in bwd
     lse_ref[0, 0] = jnp.where(denom > 0.0, m + jnp.log(safe), jnp.inf)
 
@@ -225,17 +228,23 @@ def _zero_offsets():
 
 
 def _fused_forward(q, k, v, col_idx, nvalid, *, block, causal, sliding_window,
-                   interpret, offsets=None, seq_len=None, config=None):
-    """Returns (o (N, G, S, hd), lse (N, G, S) fp32). `S` is the local row
-    count; `seq_len` (default S) is the GLOBAL sequence length used by the
-    non-causal zero-correction, and `offsets` the [row0, col0] rebasing of
-    local block indices to global ones (see module docstring)."""
+                   interpret, offsets=None, seq_len=None, config=None,
+                   head_dim):
+    """Returns (o (N, G, S, hd), lse (N, G, S, 1) fp32). q/k/v come
+    lane-padded (`_lane_pad`) for Mosaic, and `head_dim` is the true head
+    dim the softmax scale uses. `S` is the local row count; `seq_len`
+    (default S) is the GLOBAL sequence length used by the non-causal
+    zero-correction, and `offsets` the [row0, col0] rebasing of local block
+    indices to global ones (see module docstring). lse
+    carries a trailing unit axis so that its (block, 1) row slices meet
+    Mosaic's tiling rule (second-minor dim a multiple of 8, minor dim the
+    whole axis) at every block size."""
     N, G, S, hd = q.shape
     nrb, K = col_idx.shape
     config = DEFAULT_CONFIG if config is None else config
     depth = _depth(config, K)
     offsets = _zero_offsets() if offsets is None else offsets
-    scale = 1.0 / np.sqrt(hd)
+    scale = 1.0 / np.sqrt(head_dim)
     kern = functools.partial(_fwd_kernel, block=block, hd=hd, K=K,
                              depth=depth,
                              seq_len=S if seq_len is None else int(seq_len),
@@ -247,14 +256,14 @@ def _fused_forward(q, k, v, col_idx, nvalid, *, block, causal, sliding_window,
         in_specs=[
             pl.BlockSpec((1, 1, block, hd),
                          lambda n, g, r, col, nv, off: (n, g, r, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # K stays in HBM: DMA ring
-            pl.BlockSpec(memory_space=pltpu.ANY),   # V stays in HBM: DMA ring
+            pl.BlockSpec(memory_space=pltpu.HBM),   # K stays in HBM: DMA ring
+            pl.BlockSpec(memory_space=pltpu.HBM),   # V stays in HBM: DMA ring
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block, hd),
                          lambda n, g, r, col, nv, off: (n, g, r, 0)),
-            pl.BlockSpec((1, 1, block),
-                         lambda n, g, r, col, nv, off: (n, g, r)),
+            pl.BlockSpec((1, 1, block, 1),
+                         lambda n, g, r, col, nv, off: (n, g, r, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((depth, block, hd), k.dtype),    # K tile ring
@@ -263,15 +272,16 @@ def _fused_forward(q, k, v, col_idx, nvalid, *, block, causal, sliding_window,
             pltpu.SemaphoreType.DMA((depth,)),
         ],
     )
-    return pl.pallas_call(
+    o, lse = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((N, G, S, hd), q.dtype),
-                   jax.ShapeDtypeStruct((N, G, S), jnp.float32)],
+                   jax.ShapeDtypeStruct((N, G, S, 1), jnp.float32)],
         compiler_params=_compiler_params(config, interpret,
                                          ("parallel",) * 3),
         interpret=interpret,
     )(col_idx, nvalid, offsets, q, k, v)
+    return o, lse
 
 
 # ---------------------------------------------------------------------------
@@ -299,8 +309,8 @@ def _dq_kernel(col_ref, nvalid_ref, off_ref, q_ref, k_hbm, v_hbm, do_ref,
 
     q = q_ref[0, 0].astype(jnp.float32)       # (B, hd)
     do = do_ref[0, 0].astype(jnp.float32)     # (B, hd)
-    lse = lse_ref[0, 0]                       # (B,)
-    delta = delta_ref[0, 0]                   # (B,)
+    lse = lse_ref[0, 0]                       # (B, 1)
+    delta = delta_ref[0, 0]                   # (B, 1)
 
     def step(i, acc):
         ahead = i + depth - 1
@@ -319,11 +329,11 @@ def _dq_kernel(col_ref, nvalid_ref, off_ref, q_ref, k_hbm, v_hbm, do_ref,
                                 preferred_element_type=jnp.float32) * scale
         ok = _tile_mask(r + off_ref[0], col_ref[r, i] + off_ref[1], block,
                         causal, sliding_window)
-        ok &= jnp.full((block, block), i < nv)      # padded entries: ds = 0
-        p = jnp.where(ok, jnp.exp(s - lse[:, None]), 0.0)
+        ok &= i < nv                                # padded entries: ds = 0
+        p = jnp.where(ok, jnp.exp(s - lse), 0.0)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         return acc + jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
@@ -333,25 +343,27 @@ def _dq_kernel(col_ref, nvalid_ref, off_ref, q_ref, k_hbm, v_hbm, do_ref,
 
 
 def _fused_dq(q, k, v, do, lse, delta, col_idx, nvalid, *, block, causal,
-              sliding_window, interpret, offsets=None, config=None):
+              sliding_window, interpret, offsets=None, config=None,
+              head_dim):
+    """dQ (N, G, S, hd) fp32 from lane-padded q/k/v/do (see _fused_forward)."""
     N, G, S, hd = q.shape
     nrb, K = col_idx.shape
     config = DEFAULT_CONFIG if config is None else config
     depth = _depth(config, K)
     offsets = _zero_offsets() if offsets is None else offsets
-    scale = 1.0 / np.sqrt(hd)
+    scale = 1.0 / np.sqrt(head_dim)
     kern = functools.partial(_dq_kernel, block=block, hd=hd, K=K, depth=depth,
                              scale=scale, causal=causal,
                              sliding_window=sliding_window)
     qspec = pl.BlockSpec((1, 1, block, hd),
                          lambda n, g, r, col, nv, off: (n, g, r, 0))
-    anyspec = pl.BlockSpec(memory_space=pltpu.ANY)
-    rowspec = pl.BlockSpec((1, 1, block),
-                           lambda n, g, r, col, nv, off: (n, g, r))
+    hbmspec = pl.BlockSpec(memory_space=pltpu.HBM)
+    rowspec = pl.BlockSpec((1, 1, block, 1),
+                           lambda n, g, r, col, nv, off: (n, g, r, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(N, G, nrb),
-        in_specs=[qspec, anyspec, anyspec, qspec, rowspec, rowspec],
+        in_specs=[qspec, hbmspec, hbmspec, qspec, rowspec, rowspec],
         out_specs=qspec,
         scratch_shapes=[
             pltpu.VMEM((depth, block, hd), k.dtype),
@@ -390,9 +402,9 @@ def _dkv_kernel(row_ref, nvt_ref, off_ref, q_hbm, k_ref, v_ref, do_hbm,
                                       qsem.at[slot]),
                 pltpu.make_async_copy(do_hbm.at[n, g, src, :], dobuf.at[slot],
                                       dosem.at[slot]),
-                pltpu.make_async_copy(lse_hbm.at[n, g, src], lsebuf.at[slot],
-                                      lsesem.at[slot]),
-                pltpu.make_async_copy(delta_hbm.at[n, g, src],
+                pltpu.make_async_copy(lse_hbm.at[n, g, src, :],
+                                      lsebuf.at[slot], lsesem.at[slot]),
+                pltpu.make_async_copy(delta_hbm.at[n, g, src, :],
                                       dltbuf.at[slot], dltsem.at[slot]))
 
     @pl.when(g == 0)
@@ -422,17 +434,17 @@ def _dkv_kernel(row_ref, nvt_ref, off_ref, q_hbm, k_ref, v_ref, do_hbm,
         r = row_ref[c, t]
         q = qbuf[slot].astype(jnp.float32)    # (B, hd) rows of block r
         do = dobuf[slot].astype(jnp.float32)
-        lse = lsebuf[slot]
-        delta = dltbuf[slot]
+        lse = lsebuf[slot][:, :1]             # (B, 1)
+        delta = dltbuf[slot][:, :1]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         ok = _tile_mask(r + off_ref[0], c + off_ref[1], block, causal,
                         sliding_window)
-        ok &= jnp.full((block, block), t < nvt)     # padded entries: p = 0
-        p = jnp.where(ok, jnp.exp(s - lse[:, None]), 0.0)
+        ok &= t < nvt                               # padded entries: p = 0
+        p = jnp.where(ok, jnp.exp(s - lse), 0.0)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         # contract the q-row axis: dv_c += p^T dO_r ; dk_c += scale ds^T Q_r
         dv = dv + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
@@ -454,18 +466,25 @@ def _dkv_kernel(row_ref, nvt_ref, off_ref, q_hbm, k_ref, v_ref, do_hbm,
 
 
 def _fused_dkv(q, k, v, do, lse, delta, row_idx, nvalid_t, *, block, causal,
-               sliding_window, interpret, offsets=None, config=None):
+               sliding_window, interpret, offsets=None, config=None,
+               head_dim):
+    """(dK, dV) (N, Sk, hd) fp32 from lane-padded q/k/v/do (see
+    _fused_forward)."""
     N, G, S, hd = q.shape
     Sk = k.shape[1]
     ncb, KT = row_idx.shape
     config = DEFAULT_CONFIG if config is None else config
     depth = _depth(config, KT)
     offsets = _zero_offsets() if offsets is None else offsets
-    scale = 1.0 / np.sqrt(hd)
+    scale = 1.0 / np.sqrt(head_dim)
+    # lse/delta rows are DMA'd by gathered row-block, and a DMA moves whole
+    # lane tiles: broadcast the (N, G, S, 1) columns across 128 lanes
+    lse = jnp.broadcast_to(lse, (N, G, S, LANES))
+    delta = jnp.broadcast_to(delta, (N, G, S, LANES))
     kern = functools.partial(_dkv_kernel, block=block, hd=hd, KT=KT, G=G,
                              depth=depth, scale=scale, causal=causal,
                              sliding_window=sliding_window)
-    anyspec = pl.BlockSpec(memory_space=pltpu.ANY)
+    hbmspec = pl.BlockSpec(memory_space=pltpu.HBM)
     colspec = pl.BlockSpec((1, block, hd),
                            lambda n, c, g, row, nvt, off: (n, c, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -473,22 +492,22 @@ def _fused_dkv(q, k, v, do, lse, delta, row_idx, nvalid_t, *, block, causal,
         # g innermost so every revisit of the (n, c) output tile is
         # consecutive (the scratch accumulators persist across g)
         grid=(N, ncb, G),
-        in_specs=[anyspec, colspec, colspec, anyspec, anyspec, anyspec],
+        in_specs=[hbmspec, colspec, colspec, hbmspec, hbmspec, hbmspec],
         out_specs=[colspec, colspec],
         scratch_shapes=[
             pltpu.VMEM((block, hd), jnp.float32),       # dk accumulator
             pltpu.VMEM((block, hd), jnp.float32),       # dv accumulator
             pltpu.VMEM((depth, block, hd), q.dtype),    # Q row-slice ring
             pltpu.VMEM((depth, block, hd), do.dtype),   # dO row-slice ring
-            pltpu.VMEM((depth, block), jnp.float32),    # lse ring
-            pltpu.VMEM((depth, block), jnp.float32),    # delta ring
+            pltpu.VMEM((depth, block, LANES), jnp.float32),  # lse ring
+            pltpu.VMEM((depth, block, LANES), jnp.float32),  # delta ring
             pltpu.SemaphoreType.DMA((depth,)),
             pltpu.SemaphoreType.DMA((depth,)),
             pltpu.SemaphoreType.DMA((depth,)),
             pltpu.SemaphoreType.DMA((depth,)),
         ],
     )
-    return pl.pallas_call(
+    dk, dv = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((N, Sk, hd), jnp.float32),
@@ -497,6 +516,7 @@ def _fused_dkv(q, k, v, do, lse, delta, row_idx, nvalid_t, *, block, causal,
             config, interpret, ("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(row_idx, nvalid_t, offsets, q, k, v, do, lse, delta)
+    return dk, dv
 
 
 # ---------------------------------------------------------------------------
@@ -527,25 +547,36 @@ def _fused_op(block, causal, sliding_window, interpret, with_plan, seq_len,
     Every op additionally takes the `offs = [row0, col0]` block-index
     rebasing as an int32 primal (float0 cotangent); seq_len=None means "use
     q.shape[2]" — both are [0,0]/None everywhere except inside a seq shard.
+
+    The op is the one owner of the lane layout: the forward and the
+    backward each lane-pad their operands once (`_lane_pad`) and slice the
+    head dim back off, so residuals stay at the true head dim.
     """
-    fwd_ = functools.partial(_fused_forward, block=block, causal=causal,
-                             sliding_window=sliding_window,
-                             interpret=interpret, seq_len=seq_len,
-                             config=config)
+    kw = dict(block=block, causal=causal, sliding_window=sliding_window,
+              interpret=interpret, config=config)
+
+    def fwd_(q, k, v, col_idx, nvalid, *, offsets):
+        hd = q.shape[-1]
+        o, lse = _fused_forward(_lane_pad(q), _lane_pad(k), _lane_pad(v),
+                                col_idx, nvalid, offsets=offsets,
+                                seq_len=seq_len, head_dim=hd, **kw)
+        return o[..., :hd], lse
 
     def bwd_core(q, k, v, col_idx, nvalid, offs, o, lse, do, row_idx,
                  nvalid_t):
         """Shared backward body — both vjp variants differ only in where the
         transposed tables come from (plan residuals vs under-jit rebuild)."""
-        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
-        dq = _fused_dq(q, k, v, do, lse, delta, col_idx, nvalid, block=block,
-                       causal=causal, sliding_window=sliding_window,
-                       interpret=interpret, offsets=offs, config=config)
-        dk, dv = _fused_dkv(q, k, v, do, lse, delta, row_idx, nvalid_t,
-                            block=block, causal=causal,
-                            sliding_window=sliding_window, interpret=interpret,
-                            offsets=offs, config=config)
-        return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+        hd = q.shape[-1]
+        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1,
+                        keepdims=True)
+        qp, kp, vp, dop = _lane_pad(q), _lane_pad(k), _lane_pad(v), \
+            _lane_pad(do)
+        dq = _fused_dq(qp, kp, vp, dop, lse, delta, col_idx, nvalid,
+                       offsets=offs, head_dim=hd, **kw)
+        dk, dv = _fused_dkv(qp, kp, vp, dop, lse, delta, row_idx, nvalid_t,
+                            offsets=offs, head_dim=hd, **kw)
+        return (dq[..., :hd].astype(q.dtype), dk[..., :hd].astype(k.dtype),
+                dv[..., :hd].astype(v.dtype))
 
     if with_plan:
         @jax.custom_vjp
@@ -597,7 +628,7 @@ def fused_block_sparse_attention(q, k, v, col_idx, nvalid, *, block,
 
     Differentiable: jax.grad flows through Pallas dQ / dK/dV kernels (dK/dV
     sum over the G query heads of each kv head). `interpret=None` resolves
-    from the platform (compiled on TPU/GPU, interpreter elsewhere).
+    from the platform (Mosaic on TPU, interpreter on CPU).
 
     `config` is a dispatch.KernelConfig — normally the one the autotuner
     cached for this pattern (kernels/autotune.py); None means the default

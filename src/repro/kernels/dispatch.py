@@ -1,19 +1,18 @@
 """Platform-aware kernel dispatch knobs.
 
 `interpret=None` everywhere in this package means "resolve from the
-platform": Pallas kernels lower through a real compiler on COMPILED
-backends — Mosaic on TPU, Triton on GPU — and fall back to the pure-Python
-interpreter only where no compiled lane exists (CPU CI, dev laptops), so
-the same call sites run unchanged everywhere. Pass an explicit bool to
-override. GPU deliberately counts as compiled: dropping a CUDA host to the
-interpreter would silently throw away the wall-clock the kernels exist for;
-if a kernel cannot lower on a backend the failure must be loud, not a
-silent 100x slowdown.
+platform": on TPU the Pallas kernels compile through Mosaic, and the
+pure-Python interpreter runs them only where no accelerator is attached
+(CPU CI, dev laptops), so the same call sites run unchanged everywhere.
+Pass an explicit bool to override. GPU counts as an accelerator too, but
+the kernels use TPU-only primitives and have no GPU lowering: a GPU host
+fails loudly at compile time instead of dropping to a silent 100x-slower
+interpreter.
 
 Also home of `KernelConfig` — the hashable per-kernel tuning knob bundle
-(DMA pipeline depth, Mosaic dimension semantics, Triton num_warps /
-num_stages) swept by kernels/autotune.py and threaded as a jit-static
-through ops/block_sparse_attn/sharded. It lives here, not in autotune.py,
+(DMA pipeline depth, Mosaic dimension semantics) swept by
+kernels/autotune.py and threaded as a jit-static through
+ops/block_sparse_attn/sharded. It lives here, not in autotune.py,
 because block_sparse_attn needs the type and autotune imports
 block_sparse_attn (import-acyclic).
 
@@ -37,8 +36,8 @@ import jax
 _IN_SHARDED_BODY: contextvars.ContextVar[bool] = contextvars.ContextVar(
     "repro_in_sharded_kernel_body", default=False)
 
-# backends with a real Pallas compiler lane (Mosaic / Triton). Everything
-# else (cpu, METAL, ...) resolves interpret=None to the interpreter.
+# accelerator backends: interpret=None never resolves to the interpreter
+# there. Everything else (cpu, METAL, ...) runs the interpreter.
 COMPILED_BACKENDS = ("tpu", "gpu", "cuda", "rocm")
 
 
@@ -58,18 +57,14 @@ class KernelConfig:
                           (None -> all-parallel). The dK/dV grid pins its
                           own (g must stay sequential for the scratch
                           accumulators).
-      num_warps/num_stages Triton lowering knobs (GPU); None -> compiler
-                          defaults. Ignored by Mosaic and the interpreter.
 
     Changing a config can only ever change SPEED: every field controls
-    scheduling (prefetch distance, grid parallelism, warp mapping), never
+    scheduling (prefetch distance, grid parallelism), never
     the operation order inside a block, so tuned and default outputs are
     bitwise identical (tests/test_autotune.py holds this line).
     """
     depth: int = 2
     dimension_semantics: tuple | None = None
-    num_warps: int | None = None
-    num_stages: int | None = None
 
     def to_json(self) -> dict:
         d = dataclasses.asdict(self)
@@ -112,15 +107,15 @@ def in_sharded_body() -> bool:
 
 @functools.lru_cache(maxsize=1)
 def _platform_interpret() -> bool:
-    # interpret only where there is NO compiled lane — GPU (Triton) is a
-    # compiled backend exactly like TPU (Mosaic), not an interpreter host.
+    # interpret only where there is no accelerator: a GPU host must fail
+    # loudly at lowering, not fall back to the interpreter.
     return jax.default_backend() not in COMPILED_BACKENDS
 
 
 def compiled_backend() -> str | None:
-    """The compiled-lane name for this host ("tpu" / "gpu") or None.
+    """The accelerator name for this host ("tpu" / "gpu") or None.
 
-    cuda/rocm normalise to "gpu" — both lower through Triton."""
+    cuda/rocm normalise to "gpu"."""
     backend = jax.default_backend()
     if backend == "tpu":
         return "tpu"

@@ -20,9 +20,9 @@ pallas_call has no GSPMD partitioning rule, so the only alternatives under
 a mesh are the jnp BCSR path or silently replicated kernel work; the
 latter fails loudly (block_sparse_attn guard).
 
-interpret=None resolves from the platform: compiled on TPU (Mosaic) and
-GPU (Triton), Pallas interpreter only where no compiled lane exists (CPU
-CI) — the same call sites work everywhere.
+interpret=None resolves from the platform: compiled by Mosaic on TPU, the
+Pallas interpreter where no accelerator is attached (CPU CI) — the same
+call sites work everywhere.
 
 The jits here are keyed ONLY on the kernel statics (causal, sliding_window,
 block, interpret, and the autotuned KernelConfig) — never on the whole

@@ -37,9 +37,9 @@ all batch entries and heads), so sharding the leading axis changes nothing
 about the math: the sharded forward is bitwise-identical to the
 single-device kernel on each shard's rows (tested).
 
-check_rep=False for the same reason as distributed/collectives.py: the
+check_vma=False for the same reason as distributed/collectives.py: the
 replicated table inputs plus a custom_vjp body defeat shard_map's
-replication checker.
+varying-manual-axes checker.
 """
 from __future__ import annotations
 
@@ -48,7 +48,6 @@ from collections import OrderedDict
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 
 from repro.distributed import runtime
@@ -206,9 +205,9 @@ def _build_sharded_op(mesh, baxes, kv_ax, seq, block, causal, sliding_window,
                 offsets=offs, seq_len=n_seq * S, **plan_l, **kw)
             return o.reshape(B, KV, G, S, hd)
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(qspec, kvspec, kvspec) + (rep,) * n_tables,
-                     out_specs=qspec, check_rep=False)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(qspec, kvspec, kvspec) + (rep,) * n_tables,
+                         out_specs=qspec, check_vma=False)
 
 
 def sharded_fused_attention(mesh: Mesh, q, k, v, col_idx, nvalid, *, block,

@@ -11,17 +11,11 @@ stages (distributed/pipeline.py).
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.4.38; older releases have no explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - version-dependent
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    """`jax.make_mesh` with explicit-Auto axis types where the API has them."""
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
+    """`jax.make_mesh` with every axis Auto (GSPMD-partitioned)."""
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

@@ -49,18 +49,11 @@ from repro.distributed.chaos import ChaosMonkey
 from repro.distributed.fault import (DivergenceSentinel, Heartbeat,
                                      StepSupervisor, StragglerMonitor)
 from repro.distributed.sharding import mesh_context, param_shardings
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_distributed_mesh
 from repro.launch.steps import batch_pspecs, make_train_step
 from repro.models.registry import build
 from repro.optim import adamw_init
-
-# XLA flags for real TPU runs (latency-hiding scheduler = compute/comm overlap)
-TPU_XLA_FLAGS = (
-    "--xla_tpu_enable_latency_hiding_scheduler=true "
-    "--xla_tpu_megacore_fusion_allow_ags=true "
-    "--xla_enable_async_collective_permute=true "
-    "--xla_tpu_overlap_compute_collective_tc=true"
-)
 
 
 def masters_of(params):
@@ -456,8 +449,8 @@ def main():
     ap.add_argument("--sparse-kernel", default=None,
                     choices=["auto", "jnp", "fused"],
                     help="sparse-phase attention impl (default: cfg.spion.kernel; "
-                         "auto = fused Pallas kernel where a compiled lane or "
-                         "shardable mesh dim exists, jnp path elsewhere)")
+                         "auto = fused Pallas kernel on TPU or where a mesh "
+                         "dim shards it, jnp path elsewhere)")
     ap.add_argument("--coordinator", default=None, metavar="HOST:PORT",
                     help="jax.distributed coordinator (or env SPION_COORDINATOR); "
                          "with --num-processes/--process-id this process joins "
@@ -467,10 +460,14 @@ def main():
     args = ap.parse_args()
     distributed = runtime.initialize(args.coordinator, args.num_processes,
                                      args.process_id)
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    mesh = make_distributed_mesh() if distributed else None
+    # every local chip joins the mesh: without one, a multi-chip host would
+    # train on its first chip alone
+    mesh = (make_distributed_mesh()
+            if distributed or jax.device_count() > 1 else None)
     tr = Trainer(cfg, seq_len=args.seq_len, batch=args.batch,
                  ckpt_dir=args.ckpt_dir, mesh=mesh,
                  sparse_kernel=args.sparse_kernel)

@@ -1,6 +1,8 @@
 """Pallas kernel validation (interpret mode): shape/dtype sweeps vs the
 ref.py pure-jnp oracles and vs the BCSR jnp path, plus jax.grad checks of
 the fused kernel's custom VJP against the differentiable dense reference."""
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -315,8 +317,8 @@ def test_plan_path_grads_equal_fallback_path():
 
 
 def test_default_interpret_resolves_platform():
-    # GPU counts as compiled (Triton lane) — only uncompiled backends
-    # resolve interpret=None to the interpreter
+    # only backends with no accelerator resolve interpret=None to the
+    # interpreter (a GPU host fails loudly at lowering instead)
     expect = jax.default_backend() not in COMPILED_BACKENDS
     assert default_interpret(None) is expect
     assert default_interpret(True) is True
@@ -341,3 +343,22 @@ def test_kernel_wrapper_vs_bcsr_attention(arch, config, rng):
     out = spion_attention_kernel(cfg, q, k, v, b, interpret=True,
                                  config=config)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
+
+
+def test_compile_cache_dir(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise the cache goes to
+    one fixed directory inside the checkout."""
+    from repro.launch.compile_cache import DEFAULT_DIR, enable_compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(DEFAULT_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(DEFAULT_DIR)
+        root = pathlib.Path(__file__).resolve().parents[1]
+        assert DEFAULT_DIR == root / ".jax_cache"
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

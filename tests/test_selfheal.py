@@ -85,6 +85,44 @@ def test_classify_straggler_limit():
                     straggler_limit=7) == "straggler"
 
 
+@pytest.mark.parametrize("platforms,nproc,chips,ok", [
+    ("", 2, 4, False),       # two workers, one TPU lock
+    ("tpu", 2, 1, False),
+    ("cpu", 2, 4, True),     # workers never touch the TPU
+    ("", 1, 4, True),        # one worker drives every local chip
+    ("", 2, 0, True),        # not a TPU host
+])
+def test_supervisor_one_worker_per_tpu_host(monkeypatch, platforms, nproc,
+                                            chips, ok):
+    """On a TPU host the fleet is one worker: a chip belongs to one process."""
+    from repro.distributed import supervisor
+    monkeypatch.setattr(supervisor, "local_tpu_chips", lambda: chips)
+    env = {"JAX_PLATFORMS": platforms} if platforms else {}
+    make = lambda: FleetSupervisor(["true"], nproc, "/tmp/x",  # noqa: E731
+                                   env=env)
+    if ok:
+        assert make().nproc == nproc
+    else:
+        with pytest.raises(RuntimeError, match="one worker per host"):
+            make()
+
+
+def test_local_tpu_chips_counts_tpu_pci_ids_only(tmp_path):
+    """Only Google-vendor devices with a TPU device id count: a gVNIC card
+    (same vendor) and other vendors' devices do not."""
+    from repro.distributed.supervisor import local_tpu_chips
+    for name, vendor, device in [("a", "0x1ae0", "0x0063"),   # v5e chip
+                                 ("b", "0x1ae0", "0x0063"),
+                                 ("c", "0x1ae0", "0x0042"),   # gVNIC
+                                 ("d", "0x8086", "0x0063")]:
+        d = tmp_path / name
+        d.mkdir()
+        (d / "vendor").write_text(vendor + "\n")
+        (d / "device").write_text(device + "\n")
+    assert local_tpu_chips(str(tmp_path)) == 2
+    assert local_tpu_chips(str(tmp_path / "missing")) == 0
+
+
 def test_supervisor_backoff_capped():
     sup = FleetSupervisor(["true"], 1, "/tmp/x", backoff_base=1.0,
                           backoff_max=5.0)
@@ -131,10 +169,11 @@ def test_supervisor_respawns_until_budget_exhausted(tmp_path):
 
 def test_supervisor_respawn_heals_transient_crash(tmp_path):
     """Worker crashes in generation 0, succeeds in generation 1 (state via a
-    marker file — the checkpoint-resume analogue at unit scale)."""
+    marker file — the checkpoint-resume analogue at unit scale). The marker
+    is fleet-wide: a worker killed with its crashed peer before it wrote a
+    marker of its own must not crash again in generation 1."""
     code = ("import os\n"
-            "m = os.path.join(os.environ['SPION_CKPT'],\n"
-            "                 'gen0_' + os.environ['SPION_PROCESS_ID'])\n"
+            "m = os.path.join(os.environ['SPION_CKPT'], 'gen0')\n"
             "if not os.path.exists(m):\n"
             "    open(m, 'w').close()\n"
             "    raise SystemExit(1)\n")

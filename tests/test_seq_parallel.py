@@ -228,7 +228,7 @@ with mesh_context(mesh):
     assert "ppermute" in jaxpr, "halo exchange missing from the jaxpr"
     lowered = jax.jit(step).lower(*args)
     hlo = lowered.as_text()
-    assert "SPMDFullToShardShape" in hlo, "shard_map missing from HLO"
+    assert "sdy.manual_computation" in hlo, "shard_map missing from HLO"
     assert "collective_permute" in hlo, "halo exchange missing from HLO"
     lowered.compile()
     p2, _, metrics = jax.jit(step)(*args)

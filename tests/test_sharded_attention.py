@@ -58,6 +58,16 @@ def test_resolve_sparse_kernel_meshless():
     assert resolve_sparse_kernel(forced, 4, 4) == "fused"
 
 
+def test_resolve_sparse_kernel_meshless_tpu_any_chip_count(monkeypatch):
+    """On a TPU with no mesh, "auto" is the Mosaic kernel however many chips
+    the host has — never a silent jnp fallback."""
+    import jax
+    from repro.kernels import dispatch
+    monkeypatch.setattr(dispatch, "compiled_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda *a: 4)
+    assert resolve_sparse_kernel(get_config("spion-lra"), 4, 4) == "fused"
+
+
 AXES_CODE = """
 from repro.distributed.sharding import kernel_shard_axes, kernel_pspecs
 from repro.launch.mesh import make_mesh
@@ -262,9 +272,9 @@ with mesh_context(mesh):
     assert "pallas_call" in jaxpr, "auto must keep the Pallas kernel"
     lowered = jax.jit(step).lower(*args)
     hlo = lowered.as_text()
-    # shard_map manual partitioning marker in the lowered module; on TPU the
+    # shard_map lowers through Shardy as a manual computation; on TPU the
     # kernel itself additionally lowers to a tpu_custom_call
-    assert "SPMDFullToShardShape" in hlo, "shard_map missing from HLO"
+    assert "sdy.manual_computation" in hlo, "shard_map missing from HLO"
     if jax.default_backend() == "tpu":
         assert "tpu_custom_call" in hlo
     lowered.compile()   # the compile-proof on the sharded mesh
