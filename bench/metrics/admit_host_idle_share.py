@@ -1,0 +1,8 @@
+"""Share of the traced window with no operation on the device while the
+host is admitting a request (`serve.admit`: pages, the prefill's launch,
+the first token's sync), %."""
+from bench import spans
+
+
+def read(run):
+    return spans.reading(run, "admit_host_idle_share")

@@ -274,6 +274,7 @@ def _fused_forward(q, k, v, col_idx, nvalid, *, block, causal, sliding_window,
     )
     o, lse = pl.pallas_call(
         kern,
+        name="fused_fwd",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((N, G, S, hd), q.dtype),
                    jax.ShapeDtypeStruct((N, G, S, 1), jnp.float32)],
@@ -374,6 +375,7 @@ def _fused_dq(q, k, v, do, lse, delta, col_idx, nvalid, *, block, causal,
     )
     return pl.pallas_call(
         kern,
+        name="fused_dq",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, G, S, hd), jnp.float32),
         compiler_params=_compiler_params(config, interpret,
@@ -509,6 +511,7 @@ def _fused_dkv(q, k, v, do, lse, delta, row_idx, nvalid_t, *, block, causal,
     )
     dk, dv = pl.pallas_call(
         kern,
+        name="fused_dkv",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((N, Sk, hd), jnp.float32),
                    jax.ShapeDtypeStruct((N, Sk, hd), jnp.float32)],

@@ -61,6 +61,7 @@ import numpy as np
 from repro.core.attention_exec import SparseAttentionExec
 from repro.core.kv_pool import PagedKVCache, PagePool, ROOT_DIGEST
 from repro.core.sparse_attention import SparsityPlan
+from repro.launch.spans import span
 from repro.launch.steps import make_prefill_step, make_serve_step
 from repro.models.registry import build
 
@@ -262,8 +263,10 @@ class ServeEngine:
         prefilled into its slot), then decode one token for every active
         slot at its own position."""
         self._admit()
-        if any(r is not None for r in self.active):
-            self._decode_tick()
+        active = sum(r is not None for r in self.active)
+        if active:
+            with span("serve.decode", active=active):
+                self._decode_tick()
 
     def run(self, requests: List[Request]):
         """Drive `requests` (any count vs slot count) to completion."""
@@ -317,13 +320,15 @@ class ServeEngine:
             if not self.waiting or self.active[s] is not None:
                 continue
             r = self.waiting[0]
-            if self.paged:
-                self._release_done_slots()
-                first = self._admit_paged(r, s)
-                if first is None:
-                    break   # FIFO: the head of the line waits for pages
-            else:
-                first = self._prefill_into(r, s)
+            with span("serve.admit", rid=r.rid, slot=s,
+                      prompt_len=len(r.prompt)):
+                if self.paged:
+                    self._release_done_slots()
+                    first = self._admit_paged(r, s)
+                else:
+                    first = self._prefill_into(r, s)
+            if first is None:
+                break   # FIFO: the head of the line waits for pages
             self.waiting.popleft()
             r.slot = s
             r.out.append(first)
@@ -365,11 +370,13 @@ class ServeEngine:
             if r is not None:
                 tok[s, 0] = r.out[-1]
         cache = self._engine_cache()
-        logits, cache = self._decode(
-            self.params, cache, jnp.asarray(tok), jnp.asarray(posv),
-            *self._ex_args())
+        with span("serve.decode.launch"):
+            logits, cache = self._decode(
+                self.params, cache, jnp.asarray(tok), jnp.asarray(posv),
+                *self._ex_args())
         self._absorb(cache)
-        nxt = np.asarray(jnp.argmax(logits, -1))
+        with span("serve.decode.fetch"):
+            nxt = np.asarray(jnp.argmax(logits, -1))
         for s, r in enumerate(self.active):
             if r is None:
                 continue

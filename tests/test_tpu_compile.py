@@ -72,4 +72,7 @@ def test_kernel_compiles_for_v5e(one_chip, monkeypatch, kernel, shape):
     monkeypatch.setattr(bsa, "compiled_backend", lambda: "tpu")
     fn, args = _kernel_call(kernel, shape, one_chip)
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's own name (pallas_call's `name`) names its instruction
+    assert f"%fused_{kernel}." in text
