@@ -100,8 +100,8 @@ class SparsityPlan(NamedTuple):
         nvalid_t (Ly, ncb)
         block    int (static)
     `kt_star` is the TRUE max column population across all layers — the
-    static padded width of the transposed tables, so the fused VJP's dK/dV
-    grid is (N, ncb, KT*, G) instead of the always-safe (N, ncb, nrb, G).
+    static padded width of the transposed tables (the fused VJP's dK/dV
+    kernel streams only each column's nvalid_t entries of it).
     `stats` holds host-only occupancy numbers (never enters the jitted step).
     """
     tables: dict
@@ -226,6 +226,10 @@ def build_sparsity_plan(col_idx, nvalid, block: int, *, ncb: int | None = None,
         "per_layer_density": [round(float(d), 6)
                               for d in nv.sum(axis=1) / float(nrb * ncb_)],
         "dkv_grid_shrink": round(float(nrb) / float(kt), 4),
+        # the share of the padded transposed table that the dK/dV kernel
+        # skips: it streams only each column's nvalid_t listed row blocks
+        "dkv_skipped_share": 1.0 - float(nvalid_t.sum()) / float(
+            Ly * ncb_ * kt),
         # sequence-parallel halo bounds (DESIGN.md §10): the tables are one
         # stacked step input traced through the layer scan, so the shard-map
         # halo must cover every layer — the per-layer extents are kept for

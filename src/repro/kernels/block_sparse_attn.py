@@ -25,23 +25,27 @@ prologue starts DMAs 0..depth-2; loop iteration i first starts DMA
 i+depth-1 into the slot iteration i-1 just drained, then waits DMA i
 (slot i % depth) and computes. depth=1 degenerates to a synchronous
 fetch; the depth (and the Mosaic grid semantics) come from the
-`KernelConfig` the autotuner picked (kernels/autotune.py). Entries past
-`nvalid` fetch a (clamped, in-range) tile unconditionally and are masked
-out of the flash update as exact no-ops — uniform DMA traffic keeps the
-pipeline free of start/wait divergence.
+`KernelConfig` the autotuner picked (kernels/autotune.py). In the
+forward and dQ, entries past `nvalid` still fetch a (clamped, in-range)
+tile up to the table width K and are masked out of the flash update as
+exact no-ops. dK/dV instead stops at each column's own count: its loop,
+prologue and prefetch are bounded by `nvalid_t[c]`, so a padded entry is
+never fetched or computed, every DMA started is waited exactly once, and
+a column no row block lists writes zero dK/dV.
 
 Backward (flash-attention-2 style, sparse):
   dQ    — same (N, G, nrb) row-block grid as the forward, streaming the
           active KV tiles and accumulating dq = scale * sum_c ds_c K_c.
   dK/dV — column-block grid over the TRANSPOSED BCSR tables: for
-          column-block c, stream the row-blocks that reference it (and the
-          G query heads sharing the kv head, innermost so the output tile
-          is revisited consecutively) and accumulate dv += p^T dO,
-          dk += scale * ds^T Q. The transposed tables come either from a
-          host-built SparsityPlan (width KT* = true max column population,
-          precomputed at phase transition) or, as a fallback, from the
-          under-jit core.sparse_attention.bcsr_transpose at the always-safe
-          width KT = nrb.
+          column-block c, stream the nvalid_t[c] row-blocks that reference
+          it (and the G query heads sharing the kv head, innermost so the
+          output tile is revisited consecutively) and accumulate
+          dv += p^T dO, dk += scale * ds^T Q. The transposed tables come
+          either from a host-built SparsityPlan (width KT* = true max column
+          population, precomputed at phase transition) or, as a fallback,
+          from the under-jit core.sparse_attention.bcsr_transpose at the
+          always-safe width KT = nrb; the padded width sets only the table
+          shape, never the work, so both give bit-identical dK/dV.
 Both recompute p from (q, k, lse); ds = p * (dp - delta) with
 delta = rowsum(dO * O). The Alg. 6 phantom positions carry constant score 0
 and no value, so they alter only the forward normaliser — the standard
@@ -49,8 +53,9 @@ softmax cotangent identity still holds on the active pattern and gradients
 match the dense reference there (tests/test_kernels.py).
 
 Grids: fwd/dQ (N, G, nrb) with the K streaming loop INSIDE each grid step
-(that is what makes the DMA ring possible); dK/dV (N, ncb, G) with the KT
-loop inside and g innermost-sequential for the scratch accumulators.
+(that is what makes the DMA ring possible); dK/dV (N, ncb, G) with the
+nvalid_t[c] loop inside and g innermost-sequential for the scratch
+accumulators.
 
 Sequence-parallel operation (DESIGN.md §10): every kernel takes a third
 scalar-prefetch input `offs = [row0, col0]` mapping shard-local block
@@ -414,9 +419,13 @@ def _dkv_kernel(row_ref, nvt_ref, off_ref, q_hbm, k_ref, v_ref, do_hbm,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
+    # the stream stops at this column's own count: padded entries past nvt
+    # are never fetched, so every DMA started here is waited exactly once
     for j in range(min(depth - 1, KT)):
-        for cp in row_copies(j, j):
-            cp.start()
+        @pl.when(j < nvt)
+        def _prologue(j=j):
+            for cp in row_copies(j, j):
+                cp.start()
 
     k = k_ref[0].astype(jnp.float32)          # (B, hd) column block c
     v = v_ref[0].astype(jnp.float32)
@@ -425,7 +434,7 @@ def _dkv_kernel(row_ref, nvt_ref, off_ref, q_hbm, k_ref, v_ref, do_hbm,
         dk, dv = carry
         ahead = t + depth - 1
 
-        @pl.when(ahead < KT)
+        @pl.when(ahead < nvt)
         def _prefetch():
             for cp in row_copies(jax.lax.rem(ahead, depth), ahead):
                 cp.start()
@@ -442,7 +451,6 @@ def _dkv_kernel(row_ref, nvt_ref, off_ref, q_hbm, k_ref, v_ref, do_hbm,
                                 preferred_element_type=jnp.float32) * scale
         ok = _tile_mask(r + off_ref[0], c + off_ref[1], block, causal,
                         sliding_window)
-        ok &= t < nvt                               # padded entries: p = 0
         p = jnp.where(ok, jnp.exp(s - lse), 0.0)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -456,7 +464,7 @@ def _dkv_kernel(row_ref, nvt_ref, off_ref, q_hbm, k_ref, v_ref, do_hbm,
         return dk, dv
 
     dk, dv = jax.lax.fori_loop(
-        0, KT, step, (jnp.zeros((block, hd), jnp.float32),
+        0, nvt, step, (jnp.zeros((block, hd), jnp.float32),
                       jnp.zeros((block, hd), jnp.float32)))
     dk_acc[...] += dk
     dv_acc[...] += dv
@@ -538,9 +546,9 @@ def _fused_op(block, causal, sliding_window, interpret, with_plan, seq_len,
     custom_vjp identity is stable across traces).
 
     with_plan=True takes precomputed transposed tables (row_idx, nvalid_t)
-    as extra primal inputs — the host-built SparsityPlan path: the dK/dV
-    streaming width is row_idx.shape[1] = KT* (true max column population)
-    and no bcsr_transpose runs under jit. with_plan=False is the fallback
+    as extra primal inputs — the host-built SparsityPlan path: the tables
+    are row_idx.shape[1] = KT* (true max column population) wide and no
+    bcsr_transpose runs under jit. with_plan=False is the fallback
     that rebuilds the transposed tables in every backward at width KT = nrb.
 
     `config` is the (hashable) KernelConfig the autotuner resolved — part
@@ -639,10 +647,10 @@ def fused_block_sparse_attention(q, k, v, col_idx, nvalid, *, block,
     results.
 
     When a host-built SparsityPlan supplies `row_idx (ncb, KT*)` and
-    `nvalid_t (ncb,)`, the dK/dV backward streams KT* entries per column
-    block — sized to the measured pattern — and no bcsr_transpose runs
-    under jit. Without them the backward falls back to the under-jit
-    transpose at the always-safe width KT = ncb.
+    `nvalid_t (ncb,)`, no bcsr_transpose runs under jit. Without them the
+    backward falls back to the under-jit transpose at the always-safe
+    width KT = nrb. Either way the dK/dV backward streams only the
+    nvalid_t[c] listed row blocks of each column block.
 
     Sequence-parallel callers (kernels/sharded.py seq mode) pass local
     tables, `offsets = [row0, col0]` (int32 (2,), the global block index of

@@ -316,6 +316,55 @@ def test_plan_path_grads_equal_fallback_path():
         np.testing.assert_allclose(np.asarray(a), np.asarray(w), atol=1e-6)
 
 
+@pytest.mark.parametrize("causal,G,config", [
+    (False, 1, None), (True, 2, None), (False, 2, KernelConfig(depth=1)),
+], ids=["bidir", "causal-gqa", "depth1"])
+def test_dkv_padded_width_invariance(causal, G, config):
+    """dK/dV streams only each column's listed row blocks, so the padded
+    width of the transposed tables cannot change a bit of the gradients.
+    A skewed pattern: a stripe column listed by every row block but the
+    last (so KT* = nrb - 1 and pinning to nrb pads every column once more),
+    a few columns listed by one row block each, and one listed by none.
+    dQ, dK and dV agree bit for bit at KT* and at nrb, and the unlisted
+    column's dK/dV is exactly zero."""
+    S, hd, block = 128, 16, 16
+    n = S // block
+    mask = np.zeros((n, n), bool)
+    mask[: n - 1, 0] = True                 # the stripe
+    for r in range(1, n - 1):
+        mask[r, r] = True                   # listed by its own row block
+    mask[n - 1, n - 2] = True               # column n-1 is listed by none
+    b = bcsr_from_blockmask(mask, block)
+    col = jnp.maximum(b.col_idx, 0)
+    ri, nvt, kt = host_transpose_tables(b.col_idx, b.nvalid, ncb=n)
+    ri_n, nvt_n, kt_n = host_transpose_tables(b.col_idx, b.nvalid, ncb=n,
+                                              max_kt=n)
+    assert kt == n - 1 and kt_n == n and int(nvt[-1]) == 0
+    assert sorted(nvt.tolist()) == [0] + [1] * (n - 3) + [2, n - 1]
+    np.testing.assert_array_equal(nvt, nvt_n)
+    q = jax.random.normal(jax.random.key(0), (2, G, S, hd))
+    k = jax.random.normal(jax.random.key(1), (2, S, hd))
+    v = jax.random.normal(jax.random.key(2), (2, S, hd))
+    gout = jax.random.normal(jax.random.key(3), (2, G, S, hd))
+
+    def grads(row_idx, nvalid_t):
+        def loss(q, k, v):
+            o = fused_block_sparse_attention(
+                q, k, v, col, b.nvalid, block=block, causal=causal,
+                interpret=True, row_idx=jnp.asarray(row_idx),
+                nvalid_t=jnp.asarray(nvalid_t), config=config)
+            return jnp.sum(o * gout)
+        return [np.asarray(x) for x in
+                jax.grad(loss, argnums=(0, 1, 2))(q, k, v)]
+
+    tight, wide = grads(ri, nvt), grads(ri_n, nvt_n)
+    for name, a, w in zip("qkv", tight, wide):
+        assert np.array_equal(a, w), f"d{name} moved with the padded width"
+    _, dk, dv = tight
+    assert not dk[:, -block:].any() and not dv[:, -block:].any()
+    assert dk[:, :block].any() and dv[:, :block].any()
+
+
 def test_default_interpret_resolves_platform():
     # only backends with no accelerator resolve interpret=None to the
     # interpreter (a GPU host fails loudly at lowering instead)

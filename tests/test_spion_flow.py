@@ -295,3 +295,22 @@ def test_plan_stats_carry_halo_extents(rng):
     # round-trips through the JSON checkpoint channel unchanged
     st2 = SpionState.from_py(st.to_py())
     assert st2.plan_stats["halo"] == stats["halo"]
+
+
+def test_plan_stats_dkv_skipped_share(rng):
+    """The plan records the share of its padded transposed table that the
+    dK/dV kernel skips (it streams only each column's nvalid_t entries),
+    and the number survives the JSON checkpoint channel."""
+    import json
+    ctl = _controller()
+    st = SpionState()
+    for _ in range(3):
+        st = ctl.observe_epoch(st, _pooled(rng), np.array([1.0, 1.0]))
+    nvt = np.asarray(st.tables["nvalid_t"])
+    Ly, ncb = nvt.shape
+    kt = st.plan_stats["kt_star"]
+    share = st.plan_stats["dkv_skipped_share"]
+    assert 0.0 <= share < 1.0
+    assert share == 1.0 - float(nvt.sum()) / float(Ly * ncb * kt)
+    st2 = SpionState.from_py(json.loads(json.dumps(st.to_py())))
+    assert st2.plan_stats["dkv_skipped_share"] == share

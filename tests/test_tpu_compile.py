@@ -1,7 +1,8 @@
 """The fused kernels compiled by Mosaic for one described TPU v5e chip, at
 the widths the main path runs: spion-lra (head_dim 16, block 64, L 2048)
 and qwen2-7b (head_dim 128, block 128, 7 query heads per KV head, L 4096,
-causal, the depth-3 DMA ring). Interpret mode checks none of Mosaic's
+causal, the depth-3 DMA ring; dK/dV also at depth 1, which has no
+prologue). Interpret mode checks none of Mosaic's
 tiling or VMEM rules; this compile does, with no chip attached.
 
 The topology is described inside a fixture, never at import: only one
@@ -44,7 +45,7 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _kernel_call(kernel, shape, dev):
+def _kernel_call(kernel, shape, dev, depth=3):
     N, G, S, hd, block, K, dt, causal = SHAPES[shape]
     nrb = S // block
 
@@ -57,7 +58,7 @@ def _kernel_call(kernel, shape, dev):
     col, nv = sds((nrb, K), jnp.int32), sds((nrb,), jnp.int32)
     lse = sds((N, G, S, 1), jnp.float32)
     kw = dict(block=block, causal=causal, sliding_window=None,
-              interpret=False, config=KernelConfig(depth=3), head_dim=hd)
+              interpret=False, config=KernelConfig(depth=depth), head_dim=hd)
     if kernel == "fwd":
         return (lambda *a: bsa._fused_forward(*a, **kw)), (q, kv, kv, col, nv)
     fn = bsa._fused_dq if kernel == "dq" else bsa._fused_dkv
@@ -76,3 +77,13 @@ def test_kernel_compiles_for_v5e(one_chip, monkeypatch, kernel, shape):
     assert "tpu_custom_call" in text
     # the kernel's own name (pallas_call's `name`) names its instruction
     assert f"%fused_{kernel}." in text
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_dkv_depth1_compiles_for_v5e(one_chip, monkeypatch, shape):
+    # depth 1 has no prologue: each entry's fetch starts and is waited
+    # inside the loop, whose trip count is the column's own nvalid_t
+    monkeypatch.setattr(bsa, "compiled_backend", lambda: "tpu")
+    fn, args = _kernel_call("dkv", shape, one_chip, depth=1)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and "%fused_dkv." in text
