@@ -7,14 +7,15 @@
 For each seed it runs the cell as bench/run.py does (a training cell's
 window can be short: the check reads the first steps), then reads besides
 the program's numbers those of the controls, the reference computed in
-float8 and in int8 (bench/reference.py) in the program's place, and of
-the planted faults the cell can have: half of each batch left out
-(training), a served token altered (serving). A state left unchanged
-reads 1 by the training check's measure and needs no run. Each control
-and fault is judged against the cell's limits (bench/limits/<cell>.json)
-as a run is (`harness.passes`). One JSON line per seed, on standard
-output and appended to --out; a training line also holds every leaf's
-norms, so that another number can be worked out from it later.
+float8 and in int8 (the family's reference, bench/families/) in the
+program's place, and of the planted faults the cell can have: half of
+each batch left out (training), a served token altered (serving). A
+state left unchanged reads 1 by the training check's measure and needs
+no run. Each control and fault is judged against the cell's limits
+(bench/limits/<cell>.json) as a run is (`harness.passes`). One JSON line
+per seed, on standard output and appended to --out; a training line also
+holds every leaf's norms, so that another number can be worked out from
+it later.
 """
 from __future__ import annotations
 
@@ -56,10 +57,7 @@ def main(argv=None):
         return 2
     counter = harness.setup_jax()
     cell = spec.cell(args.workload)
-    kind = cell.traffic["kind"]
-    driver = __import__("bench." + {"train": "train",
-                                    "serve_open_loop": "serve"}[kind],
-                        fromlist=["run"])
+    driver = spec.driver(cell.traffic["kind"])
     for seed in [int(s) for s in args.seeds.split(",")]:
         t0 = time.perf_counter()
         run = harness.Run(cell=cell,

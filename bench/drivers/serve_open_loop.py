@@ -1,4 +1,5 @@
-"""Serving cells: `ServeEngine` under an open loop of requests.
+"""Serving cells (traffic kind `serve_open_loop`): `ServeEngine` under an
+open loop of requests.
 
 Set-up lets the program's own capture and flood fill choose the plan on
 weights and a prompt of the cache's full length drawn from the mix's plan
@@ -28,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import gen, harness, reference, weights
+from bench import gen, harness, weights
 
 CONTROLS = ("fp8", "int8")     # the reference's precisions calibrate.py reads
 
@@ -192,26 +193,6 @@ def sample(reqs, mix, seed):
     return picked
 
 
-def weights_fn(shapes, seed):
-    """name, layer -> float32 leaf, drawn again from the seed. The key is
-    made once, outside the jitted draws, so one program per leaf serves
-    every layer and every seed."""
-    flat, _ = jax.tree_util.tree_flatten_with_path(shapes)
-    table = {weights.leaf_name(p): sd for p, sd in flat}
-    key = weights.seed_key(seed)
-    draw_layer = jax.jit(weights.layer_leaf, static_argnums=(1, 2, 3))
-    draw_leaf = jax.jit(weights.unstacked_leaf, static_argnums=(1, 2, 3))
-
-    def fn(name, layer):
-        sd = table[name]
-        if layer is None:
-            x = draw_leaf(key, name, tuple(sd.shape), sd.dtype)
-        else:
-            x = draw_layer(key, name, tuple(sd.shape[1:]), sd.dtype, layer)
-        return x.astype(jnp.float32)
-    return fn
-
-
 def reference_logits(run, picked, masks_b, shapes, seed, precisions):
     """Reference logits at every served token's position of the picked
     requests: {precision: (B, R, V)}, with the served tokens (B, R) and a
@@ -233,10 +214,11 @@ def reference_logits(run, picked, masks_b, shapes, seed, precisions):
         served[b, :k] = r.out
         real[b, :k] = True
     nb = S // block
-    vis = [reference.visible(m[:nb, :nb], S, True) for m in masks_b]
+    fam = run.family
+    vis = [fam.visible(m[:nb, :nb], S, True) for m in masks_b]
     masks = [v[0] for v in vis]
-    logits = reference.decoder_logits(
-        run.model, precisions, weights_fn(shapes, seed), jnp.asarray(toks),
+    logits = fam.decoder_logits(
+        run.model, precisions, fam.weights_fn(shapes, seed), jnp.asarray(toks),
         rows, masks, vis[0][1])
     return logits, served, real
 
@@ -300,7 +282,7 @@ def run(run, *, seed, seconds, trace, t_process, compiles, control=False):
     run.record.update(steps=steps, window_end_s=end,
                       window_compiles=compiles.count,
                       prefill_bucket=eng.prefill_bucket)
-    run.memory_peak_bytes = harness.memory_peak()
+    run.memory_peak_bytes = harness.memory_peak(run.cell.chips)
 
     picked = sample(reqs, mix, seed)
     del eng

@@ -1,4 +1,5 @@
-"""Training cells: `Trainer.train` in the dense or the sparse phase.
+"""Training cells (traffic kind `train`): `Trainer.train` in the dense or
+the sparse phase, on one chip or on the mesh the traffic names.
 
 Set-up makes the weights from the seed and hands them to one `Trainer`.
 In a sparse cell the program's own capture and flood fill first choose
@@ -9,8 +10,15 @@ three steps through `Trainer.train`, the call the window makes, on
 batches whose rows all differ; those three steps are what the reference
 checks, and they also compile and warm every program the window uses.
 The window repeats `Trainer.train(1)` until --seconds have passed; a pool
-of ListOps batches made at set-up feeds it, so the window measures the
-step and not Python data generation.
+of batches of the traffic's task made at set-up feeds it, so the window
+measures the step and not Python data generation.
+
+A traffic with a `mesh` ({"seq": 2, "data": 2}, its product the cell's
+chips) trains on that mesh: the Trainer gets the mesh, and the weights,
+optimizer state and batch pool are placed as the program lays them out
+(`Trainer._restore_shardings`, `batch_pspecs`). No listed cell names a
+mesh yet: this path has run on virtual CPU devices only, never on a
+chip. The reference runs on one device.
 """
 from __future__ import annotations
 
@@ -22,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import gen, harness, reference, weights
+from bench import harness, spec, weights
 
 CHECK_STEPS = 3
 # what calibrate.py reads beside the program: (name, the reference's
@@ -94,22 +102,54 @@ def reference_run(run, precision, params0, batches, masks, totals, rows=None):
     if rows is not None:
         batches = [{k: v[:rows] for k, v in b.items()} for b in batches]
     chunk = min(run.cell.traffic["reference_chunk"], len(batches[0]["tokens"]))
-    losses, gnorms, g1, p3 = reference.train_steps(
+    losses, gnorms, g1, p3 = run.family.train_steps(
         run.model, opt, precision, params0, batches, masks, totals,
         first_step=0, chunk=chunk)
     return {"losses": losses, "gnorms": gnorms, "grad1": jax.device_get(g1),
             "delta": delta(jax.device_get(p3), params0)}
 
 
-def build(run, seed, cfg):
+def cell_mesh(traffic, chips):
+    """The mesh the traffic names, over the cell's chips; None without one.
+    Its axes' product has to be the cell's chip count."""
+    shape = dict(traffic.get("mesh") or {})
+    if not shape:
+        return None
+    if int(np.prod(list(shape.values()))) != chips:
+        raise ValueError(f"mesh {shape} does not span the cell's {chips} "
+                         f"chips")
+    from repro.launch.mesh import make_seq_mesh
+    return make_seq_mesh(**shape)
+
+
+def place_batch(cfg, mesh, b):
+    """A host batch as device arrays, split over the mesh as the program's
+    step expects (`batch_pspecs`) where there is one."""
+    if mesh is None:
+        return {k: jnp.asarray(v) for k, v in b.items()}
+    from jax.sharding import NamedSharding
+    from repro.launch.steps import batch_pspecs
+    pspecs = batch_pspecs(cfg, b, mesh)
+    return {k: jax.device_put(v, NamedSharding(mesh, pspecs[k]))
+            for k, v in b.items()}
+
+
+def build(run, seed, cfg, t_process=0.0):
     """The set-up the window and the check share: batches, weights, one
     Trainer, the plan. Returns (trainer, host batches, host weights)."""
+    from repro.distributed.sharding import mesh_context
     from repro.launch.train import Trainer, masters_of
     from repro.models.registry import build as build_model
     t = run.cell.traffic
     B, L = t["batch"], t["seq_len"]
-    batches = gen.listops_batches(seed, count=t["pool"], batch=B, seq_len=L)
-    dev_batches = [{k: jnp.asarray(v) for k, v in b.items()} for b in batches]
+    if t["pool"] < CHECK_STEPS:
+        raise ValueError(f"a pool of {t['pool']} batches cannot give the "
+                         f"{CHECK_STEPS} checked steps rows that all differ")
+    mesh = cell_mesh(t, run.cell.chips)
+    task = spec.task(t["task"])
+    batches = task.batches(seed, t, count=t["pool"],
+                           vocab_size=cfg.vocab_size)
+    dev_batches = [place_batch(cfg, mesh, b) for b in batches]
     shapes = jax.eval_shape(lambda k: masters_of(build_model(cfg).init(k)),
                             jax.random.key(0))
     params = weights.make(shapes, seed)
@@ -120,21 +160,30 @@ def build(run, seed, cfg):
         cfg = cfg.replace(spion=dataclasses.replace(
             cfg.spion, min_dense_epochs=1, max_dense_epochs=1))
     opt = t["optimizer"]
+    harness.note("batches and weights made", t_process)
     tr = Trainer(cfg, seq_len=L, batch=B, seed=0, lr=opt["lr"],
                  total_steps=opt["total_steps"],
-                 steps_per_epoch=t["steps_per_epoch"],
+                 steps_per_epoch=t["steps_per_epoch"], mesh=mesh,
                  data_fn=lambda s: dev_batches[s % len(dev_batches)])
+    # on a mesh, the state as the program lays it out there
+    shardings = tr._restore_shardings()
+    if shardings is not None:
+        tr.opt = jax.device_put(tr.opt, shardings["opt"])
+    place = (lambda p: p) if shardings is None else \
+        (lambda p: jax.device_put(p, shardings["params"]))
     if t["phase"] == "sparse":
         # the plan from weights and a batch drawn from the mix's plan seed:
         # every run seed trains under the same plan, so does the same work
-        tr.params = weights.make(shapes, t["plan_seed"])
-        plan = gen.listops_batches(t["plan_seed"], count=1, batch=B,
-                                   seq_len=L)[0]
-        tr._epoch_boundary({k: jnp.asarray(v) for k, v in plan.items()})
+        tr.params = place(weights.make(shapes, t["plan_seed"]))
+        plan = task.batches(t["plan_seed"], t, count=1,
+                            vocab_size=cfg.vocab_size)[0]
+        with mesh_context(mesh):
+            tr._epoch_boundary(place_batch(cfg, mesh, plan))
+        harness.note("plan captured and flood-filled", t_process)
         if tr.spion_state.phase != "sparse":
             raise RuntimeError("the capture did not move the trainer to the "
                                "sparse phase")
-    tr.params = params
+    tr.params = place(params)
     return tr, batches, host_params
 
 
@@ -143,7 +192,7 @@ def run(run, *, seed, seconds, trace, t_process, compiles, control=False):
     cfg = program_config(run.cell.config)
     t = run.cell.traffic
     B, L = t["batch"], t["seq_len"]
-    tr, batches, params0 = build(run, seed, cfg)
+    tr, batches, params0 = build(run, seed, cfg, t_process)
     st = tr.spion_state
     tables = st.tables if t["phase"] == "sparse" else None
     run.block = cfg.spion.block_size
@@ -163,9 +212,10 @@ def run(run, *, seed, seconds, trace, t_process, compiles, control=False):
     tr._one_step = checked_step
     tr.step_callback = lambda s, loss: losses.append(loss)
     tr.train(1, log=quiet)
+    harness.note("first step taken", t_process)
     mu1 = jax.device_get(tr.opt["mu"])
     tr.train(CHECK_STEPS - 1, log=quiet)
-    del tr._one_step
+    del tr._one_step, one_step, checked_step   # they hold the trainer
     b1 = t["optimizer"]["b1"]
     prog = {"losses": list(losses), "gnorms": [float(g) for g in gnorms],
             "grad1": jax.tree_util.tree_map(lambda m: m / (1 - b1), mu1),
@@ -198,20 +248,21 @@ def run(run, *, seed, seconds, trace, t_process, compiles, control=False):
     run.e2e["setup_s"] = run.record["setup_s"]
     run.record.update(steps=steps, window_s=dt, batch=B, seq_len=L,
                       window_compiles=compiles.count)
-    run.memory_peak_bytes = harness.memory_peak()
+    run.memory_peak_bytes = harness.memory_peak(run.cell.chips)
 
     # the check: free the program's state, then the reference
     masks_b = harness.block_masks(tables) if tables is not None \
         else np.ones((cfg.num_layers, 1, 1), bool)
     del tr
     gc.collect()
-    masks, totals = zip(*[reference.visible(m, L, run.model["causal"])
+    masks, totals = zip(*[run.family.visible(m, L, run.model["causal"])
                           for m in masks_b])
     run.record["check_inputs"] = (params0, batches[:CHECK_STEPS], masks,
                                   totals[0])
     harness.note("window over", t_process)
     ref = reference_run(run, "f32", *run.record["check_inputs"])
     nums = compare(prog, ref)
+    harness.note("reference compared", t_process)
     run.record["readings"] = dict(nums)
     if control:
         run.record["norms"] = {"program": norms_of(prog),

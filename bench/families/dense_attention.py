@@ -1,7 +1,20 @@
-"""The plain reference the benchmark compares the program with: the
-transformer of a cell's configuration in straightforward `jax.numpy`,
-float32 at full matmul precision, with no kernel, cache or batching. It
-imports nothing of the program.
+"""The `dense_attention` family: a stack of pre-norm attention and MLP
+layers (GQA, optional QKV bias and rotary or learned positions, LayerNorm
+or RMSNorm, ReLU or SiLU-gated MLP) under an embedding and an output
+head. Everything the benchmark needs of such a model besides the program:
+
+- `dims`: the model as its configuration file states it, in the names the
+  reference and the work counts use; `PROGRAM_FIELDS` and `program_dims`
+  hold the program's configuration to it;
+- the plain reference: the transformer in straightforward `jax.numpy`,
+  float32 at full matmul precision, with no kernel, cache or batching
+  (`train_steps`, `decoder_logits`, `visible`). It imports nothing of the
+  program;
+- the map from the program's weight names to the reference's
+  (`weights_fn`, `_layer_tree`);
+- the model-level work counts (`matmul_params`, `weight_bytes`,
+  `decode_cache_bytes`, `train_flops_per_step`, `prefill_flops`); the
+  kernels' own counts are in bench/flops.py.
 
 Attention follows SPION's sparse softmax (arXiv:2309.12578, Alg. 6): row
 i attends the positions its plan row lists (and, for a decoder, only
@@ -10,7 +23,7 @@ score of 0 in the denominator and adds no value. With every block listed
 this is the ordinary softmax.
 
 `PRECISIONS` picks how each matmul rounds its operands: `f32` rounds
-nothing; `fp8` is the control, a float8 (e4m3) matmul with a scale per
+nothing; `fp8` is a control, a float8 (e4m3) matmul with a scale per
 tensor on its operands, forward and backward, the step below the bfloat16
 the configurations state; `int8` is the same with int8 operands.
 """
@@ -22,9 +35,54 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import flops, weights
+
 HIGHEST = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0
 I8_MAX = 127.0
+
+
+# published (Hugging Face style) keys -> the program's ModelConfig fields
+PROGRAM_FIELDS = {
+    "hidden_size": "d_model", "num_hidden_layers": "num_layers",
+    "num_attention_heads": "num_heads", "num_key_value_heads": "num_kv_heads",
+    "intermediate_size": "d_ff", "vocab_size": "vocab_size",
+    "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
+    "layer_norm_eps": "norm_eps", "tie_word_embeddings": "tie_embeddings",
+    "torch_dtype": "dtype", "qkv_bias": "qkv_bias",
+}
+
+
+def dims(conf: dict) -> dict:
+    """The model as the configuration file states it, in the names the
+    reference and the work counts use."""
+    d, h = conf["hidden_size"], conf["num_attention_heads"]
+    return {
+        "d_model": d, "num_heads": h,
+        "num_kv_heads": conf.get("num_key_value_heads", h),
+        "head_dim": conf.get("head_dim", d // h),
+        "d_ff": conf["intermediate_size"],
+        "num_layers": conf["num_hidden_layers"],
+        "vocab_size": conf["vocab_size"],
+        "gated_mlp": conf["hidden_act"] == "silu",
+        "qkv_bias": bool(conf.get("qkv_bias", False)),
+        "norm": "rmsnorm" if "rms_norm_eps" in conf else "layernorm",
+        "norm_eps": conf.get("rms_norm_eps", conf.get("layer_norm_eps")),
+        "rope_theta": conf.get("rope_theta", 0.0),
+        "causal": bool(conf["causal"]),
+        "dtype": conf["torch_dtype"],
+    }
+
+
+def program_dims(cfg) -> dict:
+    """The program's ModelConfig read in `dims`' names, for the keys both
+    state."""
+    return {"d_model": cfg.d_model, "num_heads": cfg.num_heads,
+            "num_kv_heads": cfg.num_kv_heads,
+            "head_dim": cfg.resolved_head_dim, "d_ff": cfg.d_ff,
+            "num_layers": cfg.num_layers, "vocab_size": cfg.vocab_size,
+            "gated_mlp": cfg.act == "silu", "qkv_bias": cfg.qkv_bias,
+            "causal": cfg.causal, "dtype": cfg.dtype}
 
 
 def q8(x):
@@ -322,3 +380,81 @@ def _layer_tree(m, weights_fn, i):
         tree.setdefault(a, {})[b] = weights_fn("layers/" + n, i).astype(
             jnp.float32)
     return tree
+
+
+def weights_fn(shapes, seed):
+    """name, layer -> float32 leaf of the program's tree `shapes`, drawn
+    again from the seed. The key is made once, outside the jitted draws, so
+    one program per leaf serves every layer and every seed."""
+    flat, _ = jax.tree_util.tree_flatten_with_path(shapes)
+    table = {weights.leaf_name(p): sd for p, sd in flat}
+    key = weights.seed_key(seed)
+    draw_layer = jax.jit(weights.layer_leaf, static_argnums=(1, 2, 3))
+    draw_leaf = jax.jit(weights.unstacked_leaf, static_argnums=(1, 2, 3))
+
+    def fn(name, layer):
+        sd = table[name]
+        if layer is None:
+            x = draw_leaf(key, name, tuple(sd.shape), sd.dtype)
+        else:
+            x = draw_layer(key, name, tuple(sd.shape[1:]), sd.dtype, layer)
+        return x.astype(jnp.float32)
+    return fn
+
+
+# -- work counts --------------------------------------------------------------
+
+def matmul_params(cfg) -> int:
+    """Parameters that enter a matmul once per token: attention and MLP
+    projections of every layer, and the output head."""
+    d, hd = cfg["d_model"], cfg["head_dim"]
+    h, kv = cfg["num_heads"], cfg["num_kv_heads"]
+    attn = d * h * hd * 2 + 2 * d * kv * hd
+    mlp = (3 if cfg["gated_mlp"] else 2) * d * cfg["d_ff"]
+    return cfg["num_layers"] * (attn + mlp) + d * cfg["vocab_size"]
+
+
+def train_flops_per_step(cfg, plan_nvalid, *, batch, seq_len, block):
+    """Model FLOPs of one training step: 6 per matmul parameter and token,
+    and 3x the forward's 4 hd per listed score. Recomputation not counted."""
+    mm = 6 * matmul_params(cfg) * batch * seq_len
+    att = 3 * 4 * cfg["head_dim"] * cfg["num_heads"] * batch * \
+        flops.attention_scores(plan_nvalid, block)
+    return mm + att
+
+
+def prefill_flops(cfg, plan_nvalid, *, prompt_len, block):
+    """Model FLOPs of one prompt: 2 per matmul parameter and prompt token
+    (the output head once, for the token that is served), and 4 hd per
+    listed score over the row blocks that hold prompt rows."""
+    d, vocab = cfg["d_model"], cfg["vocab_size"]
+    body = matmul_params(cfg) - d * vocab
+    rows = -(-prompt_len // block)
+    real = [min(block, prompt_len - r * block) for r in range(rows)]
+    att = 4 * cfg["head_dim"] * cfg["num_heads"] * \
+        flops.attention_scores(plan_nvalid, block, rows=rows, row_tokens=real)
+    return 2 * body * prompt_len + 2 * d * vocab + att
+
+
+def weight_bytes(cfg) -> int:
+    """Bytes of weights one decode tick reads: every matmul parameter and
+    the norms, at the served dtype (embedding rows are negligible)."""
+    d = cfg["d_model"]
+    norms = cfg["num_layers"] * 2 * d + d
+    bias = cfg["num_layers"] * (cfg["num_heads"] + 2 * cfg["num_kv_heads"]) \
+        * cfg["head_dim"] if cfg["qkv_bias"] else 0
+    return (matmul_params(cfg) + bias) * flops.itemsize(cfg["dtype"]) + \
+        norms * flops.F32
+
+
+def decode_cache_bytes(cfg, plan_nvalid, positions, block):
+    """Cache bytes one decode tick has to read: for every active slot at
+    position p, in every layer, K and V of the blocks its row block lists."""
+    per_block = 2 * block * cfg["num_kv_heads"] * cfg["head_dim"] * \
+        flops.itemsize(cfg["dtype"])
+    total = 0
+    for nv in plan_nvalid:
+        nv = np.asarray(nv)
+        for p in positions:
+            total += int(nv[min(int(p) // block, len(nv) - 1)])
+    return total * per_block

@@ -1,12 +1,13 @@
 """Seeded traffic for the benchmark's cells.
 
-Training batches are ListOps (Nangia & Bowman 2018, the LRA task the
-paper trains on), from a copy of the grammar the repository's data module
-uses. Serving requests are an open loop: a fixed count of arrivals spread
-uniformly over the window (a Poisson process conditioned on its count),
-with prompt and output lengths drawn once from the mix's population seed
-and dealt out in the run seed's order, so every seed serves the same
-work.
+Training batches come from the traffic's `task`, a generator of its own
+found by name (bench/tasks/<task>.py). ListOps (Nangia & Bowman 2018, the
+LRA task the paper trains on) is made here, from a copy of the grammar
+the repository's data module uses. Serving requests are an open loop: a
+fixed count of arrivals spread uniformly over the window (a Poisson
+process conditioned on its count), with prompt and output lengths drawn
+once from the mix's population seed and dealt out in the run seed's
+order, so every seed serves the same work.
 """
 from __future__ import annotations
 

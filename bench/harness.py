@@ -40,6 +40,14 @@ class Run:
         return self.cell.model
 
     @property
+    def family(self):
+        return self.cell.family
+
+    @property
+    def chips(self):
+        return self.cell.chips
+
+    @property
     def correct(self):
         return passes(self.checks)
 
@@ -139,9 +147,10 @@ def note(what, t_process):
           file=sys.stderr, flush=True)
 
 
-def memory_peak():
-    stats = jax.devices()[0].memory_stats() or {}
-    return int(stats.get("peak_bytes_in_use", 0))
+def memory_peak(chips=1):
+    """The largest peak of bytes in use over the cell's chips."""
+    return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+               for d in jax.devices()[:chips])
 
 
 def nearest_rank(values, q):
@@ -167,16 +176,10 @@ def run_cell(cell, *, seed, seconds, trace, t_process):
     counter = setup_jax()
     dev = jax.devices()[0]
     peaks = peak_table.peaks_for(dev.device_kind)
-    kind = cell.traffic["kind"]
-    if kind == "train":
-        from bench import train as driver
-    elif kind == "serve_open_loop":
-        from bench import serve as driver
-    else:
-        raise ValueError(f"unknown traffic kind {kind!r}")
     run = Run(cell=cell, peaks=peaks)
-    driver.run(run, seed=seed, seconds=seconds, trace=trace,
-               t_process=t_process, compiles=counter)
+    spec.driver(cell.traffic["kind"]).run(
+        run, seed=seed, seconds=seconds, trace=trace, t_process=t_process,
+        compiles=counter)
     return result(run, trace)
 
 

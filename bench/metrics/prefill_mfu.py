@@ -1,7 +1,7 @@
 """Model FLOPs of the prompts prefilled in the traced window (the output
 head once per prompt, attention over the listed blocks) over the device
 time of the prefill program times the bf16 peak, %."""
-from bench import flops, readers
+from bench import readers
 
 PROGRAM = "jit_prefill"
 
@@ -13,6 +13,7 @@ def read(run):
     lens = [p for s in readers.traced_steps(run) for p in s["admitted"]]
     if not calls or seconds <= 0 or not lens:
         return None
-    work = sum(flops.prefill_flops(run.model, run.plan_nvalid, prompt_len=p,
-                                   block=run.block) for p in lens)
+    work = sum(run.family.prefill_flops(run.model, run.plan_nvalid,
+                                        prompt_len=p, block=run.block)
+               for p in lens)
     return 100.0 * work / (seconds * run.peaks["flops_bf16"])

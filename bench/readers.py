@@ -25,7 +25,9 @@ def idle_share(run):
 
 def train_kernel_roofline(run, kind):
     """A training kernel's summed roofline time over its summed device time
-    in the trace, %; each call counted as the mean layer's work."""
+    in the trace, %; the calls counted over all chips, each chip's share of
+    a layer's call standing for 1/chips of the whole batch's work at the
+    mean layer's plan (work a chip repeats for another counts once)."""
     t = run.trace
     if t is None or run.plan_nvalid is None:
         return None
@@ -48,7 +50,7 @@ def train_kernel_roofline(run, kind):
             f, b = flops.fused_dkv(kv_heads=m["num_kv_heads"], cols=L // blk,
                                    **kw)
         per_layer.append(flops.roofline_s(f, b, run.peaks))
-    return 100.0 * calls * float(np.mean(per_layer)) / seconds
+    return 100.0 * (calls / run.chips) * float(np.mean(per_layer)) / seconds
 
 
 def traced_steps(run):

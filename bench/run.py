@@ -7,7 +7,8 @@ The cell is an entry of BENCHMARK.json's `workloads`: a configuration, a
 traffic mix and a chip count, found by name (bench/spec.py). The run makes
 its weights and inputs from --seed, sets up and warms every shape the cell
 uses, measures for --seconds, checks what the timed path produced against
-the plain reference (bench/reference.py), and prints one JSON object as
+the plain reference of the configuration's family
+(bench/families/<family>.py), and prints one JSON object as
 its last line: the cell's end-to-end metrics with --trace 0, its per-layer
 metrics (read from a profiler trace of part of the window) with --trace 1.
 The numbers the correctness check compared, each with its limit, are the

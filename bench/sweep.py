@@ -34,13 +34,14 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=1)
     args = ap.parse_args(argv)
     import jax
-    from bench import gen, harness, peaks, serve, spec
+    from bench import gen, harness, peaks, spec
     from bench.spec import program_config
     if jax.devices()[0].platform != "tpu":
         print("sweep: needs a TPU", file=sys.stderr)
         return 2
     harness.setup_jax()
     cell = spec.cell(args.workload)
+    serve = spec.driver(cell.traffic["kind"])
     cfg = program_config(cell.config)
     run = harness.Run(cell=cell,
                       peaks=peaks.peaks_for(jax.devices()[0].device_kind))

@@ -4,6 +4,7 @@ seconds, through the same driver functions the chip runs. (A module of
 its own and not a conftest.py: the tests above import their conftest by
 name.)"""
 import os
+import subprocess
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
@@ -44,10 +45,21 @@ def small_serve_cell():
 def drive(cell, *, seed=2**31 + 11, seconds=1.0, **kw):
     """One run of a cell's driver; returns (Run, result line)."""
     run = harness.Run(cell=cell, peaks=PEAKS)
-    driver = {"train": "bench.train", "serve_open_loop": "bench.serve"}[
-        cell.traffic["kind"]]
-    mod = __import__(driver, fromlist=["run"])
-    mod.run(run, seed=seed, seconds=seconds, trace=False, t_process=0.0,
-            compiles=harness.CompileCounter(), **kw)
+    spec.driver(cell.traffic["kind"]).run(
+        run, seed=seed, seconds=seconds, trace=False, t_process=0.0,
+        compiles=harness.CompileCounter(), **kw)
     return run, harness.result(run, False)
 
+
+def run_on_cpu_devices(code, devices=4):
+    """`code` in a fresh interpreter with `devices` virtual CPU devices (JAX
+    fixes the count when it starts); returns its standard output."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    env = {"PYTHONPATH": os.pathsep.join([ROOT, os.path.join(ROOT, "src"),
+                                          here]),
+           "XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}",
+           "JAX_PLATFORMS": "cpu", "PATH": os.environ.get("PATH", "")}
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=env, cwd=ROOT, timeout=600)
+    assert r.returncode == 0, r.stderr[-3000:]
+    return r.stdout

@@ -10,7 +10,10 @@ import numpy as np
 import pytest
 from bench_cases import ROOT, drive, small_serve_cell, small_train_cell
 
-from bench import harness, reference, train
+from bench import harness, spec
+
+reference = spec.part("families", "dense_attention")
+train = spec.driver("train")
 
 # limits for the sound runs at these reduced sizes, between their readings
 # here (loss 1e-4, gradient norm before the clip 8e-3, first gradient 2e-2,
@@ -153,7 +156,7 @@ def test_serve_control_and_fault_readings():
 
 
 def test_a_compile_in_the_window_fails_the_run(monkeypatch):
-    from bench import serve
+    serve = spec.driver("serve_open_loop")
     monkeypatch.setattr(serve, "warm", lambda *a, **k: [])
     run, res = drive(limited(small_serve_cell(), SERVE_LIMITS))
     assert res["checks"]["window_compiles"]["value"] > 0

@@ -6,6 +6,8 @@ import pytest
 import bench_cases  # noqa: F401  (puts the repository on the path)
 from bench import flops, spec
 
+dense = spec.part("families", "dense_attention")
+
 PEAKS = {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9}
 
 
@@ -50,25 +52,25 @@ def test_scores_follow_the_plan():
 
 
 def test_train_flops_of_the_paper_model():
-    m = spec.model_dims(spec.read_json("bench/configs/spion-lra.json"))
-    mm = flops.matmul_params(m)
+    m = dense.dims(spec.read_json("bench/configs/spion-lra.json"))
+    mm = dense.matmul_params(m)
     assert mm == 4 * (4 * 64 * 64 + 2 * 64 * 256) + 64 * 512
-    dense = [np.full(32, 32)] * 4
-    f = flops.train_flops_per_step(m, dense, batch=128, seq_len=2048,
+    plan = [np.full(32, 32)] * 4
+    f = dense.train_flops_per_step(m, plan, batch=128, seq_len=2048,
                                    block=64)
     att = 3 * 4 * 16 * 4 * 128 * 4 * 2048 * 2048
     assert f == 6 * mm * 128 * 2048 + att
 
 
 def test_serving_counts():
-    m = spec.model_dims(spec.read_json("bench/configs/qwen2-7b-16l.json"))
+    m = dense.dims(spec.read_json("bench/configs/qwen2-7b-16l.json"))
     layers = 16 * (2 * 3584 * 3584 + 2 * 3584 * 512 + 3 * 3584 * 18944)
-    assert flops.matmul_params(m) == layers + 3584 * 152064
-    w = flops.weight_bytes(m)
+    assert dense.matmul_params(m) == layers + 3584 * 152064
+    w = dense.weight_bytes(m)
     assert 8.0e9 < w < 8.6e9
     nv = [np.ones(18, int)] * 16
-    assert flops.decode_cache_bytes(m, nv, [5, 1000], 128) == \
+    assert dense.decode_cache_bytes(m, nv, [5, 1000], 128) == \
         16 * 2 * (2 * 128 * 4 * 128 * 2)
-    p = flops.prefill_flops(m, nv, prompt_len=300, block=128)
+    p = dense.prefill_flops(m, nv, prompt_len=300, block=128)
     assert p == 2 * layers * 300 + 2 * 3584 * 152064 + \
         4 * 128 * 28 * 16 * (128 + 128 + 44) * 128
